@@ -34,8 +34,7 @@ class DatasetMeta:
     vocab: int = 0
 
 
-def _separable_labels(x: np.ndarray, w: np.ndarray, noise: float, rng) -> np.ndarray:
-    margin = x @ w
+def _separable_labels(margin: np.ndarray, noise: float, rng) -> np.ndarray:
     y = np.sign(margin)
     flip = rng.random(len(y)) < noise
     y[flip] *= -1
@@ -61,9 +60,9 @@ def make_classification_dataset(
                 nnz = int(rng.integers(nnz_range[0], nnz_range[1] + 1))
                 idx = rng.choice(dim, size=nnz, replace=False).astype(np.uint32)
                 val = rng.normal(size=nnz).astype(np.float32)
-                x = np.zeros(dim, np.float32)
-                x[idx] = val
-                y = _separable_labels(x[None], w_true, noise, rng)[0]
+                # O(nnz): only the nonzeros meet w_true (dim may be 30M)
+                margin = val.astype(np.float64) @ w_true[idx]
+                y = _separable_labels(np.array([margin]), noise, rng)[0]
                 rec = struct.pack("<fI", y, nnz) + idx.tobytes() + val.tobytes()
                 w.append(rec)
                 total += len(rec)
@@ -72,7 +71,7 @@ def make_classification_dataset(
         with RecordWriter(path, record_size=rec_size) as w:
             for _ in range(num_records):
                 x = rng.normal(size=dim).astype(np.float32)
-                y = _separable_labels(x[None], w_true, noise, rng)[0]
+                y = _separable_labels(x[None] @ w_true, noise, rng)[0]
                 w.append(struct.pack("<f", y) + x.tobytes())
                 total += rec_size
     return DatasetMeta(

@@ -146,7 +146,7 @@ def batch_gather_dma(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((m * r, bd), lambda i, j, idx: (i, j)),
             scratch_shapes=[
                 pltpu.VMEM((2, r, bd), table.dtype),

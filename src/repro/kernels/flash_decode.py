@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -106,7 +105,7 @@ def flash_decode(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

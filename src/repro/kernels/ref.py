@@ -18,10 +18,10 @@ def batch_gather_ref(table, indices, rows_per_block: int = 1):
 def csr_dot_ref(indices, values, w):
     """Padded-CSR inner products: ``out[b] = Σ_k values[b,k]·w[indices[b,k]]``.
 
-    The einsum-style oracle for the Pallas ``csr_dot`` kernel.  Jitted so
-    the comparison is bit-exact: XLA's compiled gather→mul→reduce emits
-    the same accumulation order at any leading batch extent, whereas the
-    eager path reassociates differently (~1 ulp)."""
+    The oracle for ``ops.csr_dot``.  Jitted so the comparison is
+    bit-exact: XLA's compiled gather→mul→reduce emits the same
+    accumulation order at any leading batch extent, whereas the eager
+    path reassociates differently (~1 ulp)."""
     gathered = w.astype(jnp.float32)[indices]
     return jnp.sum(values.astype(jnp.float32) * gathered, axis=-1)
 

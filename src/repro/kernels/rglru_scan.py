@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, block_t):
@@ -65,7 +64,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((bb, bt, bw), lambda bi, wi, ti: (bi, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((b, t, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bw), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,5 +1,6 @@
-"""Shared launcher flags for the read path — declared once, parsed into
-:class:`~repro.core.readpath.ReadPathConfig`.
+"""Shared launcher flags — declared once for ``launch/train.py`` and
+``launch/serve.py``: the model (:func:`add_model_args`) and the read
+path, parsed into :class:`~repro.core.readpath.ReadPathConfig`.
 
 ``launch/train.py`` and ``launch/serve.py`` both front the same tiered
 read path; before this module each mirrored the knob set as its own
@@ -14,9 +15,35 @@ from __future__ import annotations
 import argparse
 from typing import Optional
 
+from repro.configs import ARCH_IDS, get_config
 from repro.core.readpath import ReadPathConfig
+from repro.models.config import ModelConfig
 
 SHUFFLER_CHOICES = ("lirs", "lirs_page", "bmf", "tfip", "corgipile", "corgi2")
+
+
+def add_model_args(
+    ap: argparse.ArgumentParser, default_arch: str
+) -> argparse.ArgumentParser:
+    """Declare the model flags: architecture, reduced widths, depth cut."""
+    ap.add_argument("--arch", default=default_arch, choices=ARCH_IDS)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced widths and vocab (CPU)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: keep the first N layers at the "
+                         "configuration's widths; N must be a whole number "
+                         "of periods of the layer pattern (0 = full depth)")
+    return ap
+
+
+def model_config_from_args(args) -> ModelConfig:
+    """The :class:`ModelConfig` the :func:`add_model_args` flags name."""
+    cfg = get_config(args.arch, smoke=args.smoke)
+    if args.smoke:
+        cfg = cfg.replace(vocab_size=min(cfg.vocab_size, 512))
+    if args.layers:
+        cfg = cfg.with_layers(args.layers)
+    return cfg
 
 
 def add_read_path_args(ap: argparse.ArgumentParser) -> argparse.ArgumentParser:

@@ -28,9 +28,13 @@ import time
 
 import jax
 
-from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import make_classification_dataset
-from repro.launch.args import add_read_path_args
+from repro.launch.args import (
+    add_model_args,
+    add_read_path_args,
+    model_config_from_args,
+)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import (
     RequestStreamCache,
@@ -45,8 +49,7 @@ from repro.storage.record_store import RecordStore
 def build_argparser():
     ap = argparse.ArgumentParser()
     add_read_path_args(ap)
-    ap.add_argument("--arch", default="granite-3-8b", choices=ARCH_IDS)
-    ap.add_argument("--smoke", action="store_true")
+    add_model_args(ap, default_arch="granite-3-8b")
     ap.add_argument("--serve-mode", default="continuous",
                     choices=["continuous", "static"],
                     help="continuous = in-flight batching (free slots "
@@ -76,9 +79,8 @@ def build_argparser():
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
-    cfg = get_config(args.arch, smoke=args.smoke)
-    if args.smoke:
-        cfg = cfg.replace(vocab_size=min(cfg.vocab_size, 512))
+    enable_compile_cache()
+    cfg = model_config_from_args(args)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
 
     feature_cache = None
@@ -138,6 +140,8 @@ def main(argv=None):
         "requests": len(completions),
         "offered_load": args.offered_load,
         "generated_tokens": tokens,
+        "completion_tokens": sum(len(c.tokens) for c in completions),
+        "slot_leaks": engine.max_batch - engine.free_slots,
         "decode_steps": engine.decode_steps,
         "tokens_per_step": round(tokens / max(engine.decode_steps, 1), 3),
         "tokens_per_s": round(tokens / max(wall, 1e-9), 1),

@@ -6,8 +6,12 @@
 Wires: synthetic token corpus in a RecordStore → shuffle strategy (LIRS /
 BMF / TFIP / CorgiPile / Corgi²) →
 prefetching pipeline → jitted train step → checkpoints + Eq. 1 report.
-On a multi-device host it shards the batch over a ("data","model") mesh;
-on this CPU box it runs single-device with identical code paths.
+The step is one unsharded program on the default device (one chip, or
+the CPU); ``--hosts N`` multiplies the I/O plane, not the compute.
+``--layers N`` cuts a published configuration's depth to fit one chip:
+
+    PYTHONPATH=src python -m repro.launch.train --arch granite-3-8b \
+        --layers 1 --seq-len 4096 --batch 1 --num-records 16 --steps 32
 """
 from __future__ import annotations
 
@@ -15,15 +19,17 @@ import argparse
 import json
 import tempfile
 
-from repro.configs import ARCH_IDS, get_config
 from repro.core.readpath import build_data_plane
 from repro.data.synthetic import decode_token_batch, make_token_dataset
 from repro.launch.args import (
+    add_model_args,
     add_read_path_args,
     config_from_args,
     make_shuffler_from_args,
+    model_config_from_args,
     planner_from_args,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.storage.faults import FaultInjector, FaultSpec
@@ -35,8 +41,7 @@ from repro.train.optimizer import AdamWConfig
 def build_argparser():
     ap = argparse.ArgumentParser()
     add_read_path_args(ap)
-    ap.add_argument("--arch", default="minitron-8b", choices=ARCH_IDS)
-    ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    add_model_args(ap, default_arch="minitron-8b")
     ap.add_argument("--num-records", type=int, default=512)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=16)
@@ -87,12 +92,11 @@ def build_argparser():
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
+    enable_compile_cache()
     if args.trace:
         obs_trace.enable()
     registry = obs_metrics.reset_registry()
-    cfg = get_config(args.arch, smoke=args.smoke)
-    if args.smoke:
-        cfg = cfg.replace(vocab_size=min(cfg.vocab_size, 512))
+    cfg = model_config_from_args(args)
 
     injector = (
         FaultInjector(FaultSpec.parse(args.chaos)) if args.chaos else None
@@ -103,7 +107,7 @@ def main(argv=None):
         d = tempfile.mkdtemp(prefix="lirs_data_")
         meta = make_token_dataset(
             f"{d}/corpus.rrec", args.num_records, args.seq_len,
-            min(cfg.vocab_size, 512) if args.smoke else cfg.vocab_size,
+            cfg.vocab_size,
             seed=args.seed,
         )
         path = meta.path

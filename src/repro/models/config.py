@@ -141,6 +141,29 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    def with_layers(self, layers: int) -> "ModelConfig":
+        """Depth cut: the first ``layers`` layers of the stack, as whole
+        repeats of each stage's pattern; every width stays as published.
+        Refuses a depth that would end inside a pattern period."""
+        if not 1 <= layers <= self.num_layers:
+            raise ValueError(
+                f"layers={layers} outside 1..{self.num_layers} for {self.name}"
+            )
+        stages, left = [], layers
+        for pattern, repeats in self.stages:
+            period = len(pattern)
+            take = min(repeats, left // period)
+            if take < repeats and left % period:
+                raise ValueError(
+                    f"layers={layers} is not a whole number of periods of "
+                    f"{self.name}'s layer pattern {pattern}"
+                )
+            stages.append((pattern, take))
+            left -= take * period
+            if left == 0:
+                break
+        return self.replace(stages=tuple(stages))
+
     # Parameter count (exact — from abstract init; for MODEL_FLOPS = 6·N·D)
     def param_count(self, active_only: bool = False) -> int:
         from repro.models import model as _model  # lazy, avoids cycle

@@ -10,8 +10,8 @@ epoch) — which is exactly the variable the paper studies.
 ``solve_block_csr`` consumes CSR batches straight off the ragged read
 path (repro.svm.sparse) without densifying: the sequential dual updates
 touch only each instance's nonzeros, and the O(B·nnz) batch inner
-products (``margins_csr``) run on-device through the Pallas ``csr_dot``
-segment-gather kernel.
+products (``margins_csr``) run on-device through ``ops.csr_dot``, a
+gather-and-reduce over the dense weight vector.
 """
 from __future__ import annotations
 
@@ -92,7 +92,7 @@ class DCDSolver:
         return np.bincount(rc[starts], combined * combined, minlength=b)
 
     def margins_csr(self, csr) -> np.ndarray:
-        """Batch inner products ``X w`` on-device (Pallas csr_dot)."""
+        """Batch inner products ``X w`` on-device (``ops.csr_dot``)."""
         import jax.numpy as jnp
 
         from repro.kernels import ops

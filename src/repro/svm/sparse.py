@@ -14,7 +14,7 @@ the parity oracle for the vectorized path.
 
 ``pad_csr`` rectangularizes a CSR batch to ``(B, K)`` index/value arrays
 (pad index 0, pad value 0.0 — an exact no-op for any inner product), the
-shape the Pallas ``csr_dot`` kernel consumes on-device.
+shape ``ops.csr_dot`` consumes on-device.
 """
 from __future__ import annotations
 
@@ -144,8 +144,8 @@ def pack_csr_batch(
 def pad_csr(
     csr: CSRBatch, k: int = 0, multiple: int = 8
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Rectangularize to ``(B, K)`` padded index/value arrays for the
-    Pallas ``csr_dot`` kernel.
+    """Rectangularize to ``(B, K)`` padded index/value arrays for
+    ``ops.csr_dot``.
 
     Padding uses index 0 with value 0.0, which contributes exactly
     ``0.0 * w[0] == 0.0`` to any inner product (bit-exact no-op for

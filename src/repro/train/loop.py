@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import statistics
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -143,6 +145,7 @@ class Trainer:
                         continue
                     if lc.fail_at_step >= 0 and self.global_step == lc.fail_at_step:
                         raise PreemptionError(f"simulated preemption @ {self.global_step}")
+                    t0 = time.perf_counter()
                     with _trace.span(
                         "train/step",
                         "train",
@@ -153,7 +156,7 @@ class Trainer:
                         self.state, metrics = self.step_fn(self.state, batch)
                     self.global_step += 1
                     step_in_epoch += 1
-                    self._log(epoch, metrics)
+                    self._log(epoch, metrics, t0)
                     if self.ckpt and self.global_step % lc.ckpt_every == 0:
                         self._save(epoch, step_in_epoch)
                     if lc.max_steps and self.global_step >= lc.max_steps:
@@ -180,21 +183,35 @@ class Trainer:
             extra={"epoch": epoch, "step_in_epoch": step_in_epoch},
         )
 
-    def _log(self, epoch: int, metrics: Dict):
+    def _log(self, epoch: int, metrics: Dict, t0: float):
         rec = {
             "step": self.global_step,
             "epoch": epoch,
             **{k: float(v) for k, v in metrics.items()},
         }
+        # float() above waited for the step: wall time of the dispatched
+        # step, compile included on the first
+        rec["step_s"] = time.perf_counter() - t0
         self.history.append(rec)
         if self._log_f:
             self._log_f.write(json.dumps(rec) + "\n")
 
     def summary(self) -> Dict[str, Any]:
         s = self.pipeline.stats
+        step_s = [h["step_s"] for h in self.history]
         return {
             "steps": self.global_step,
             "final_loss": self.history[-1]["loss"] if self.history else None,
+            "losses": [h["loss"] for h in self.history],
+            "first_step_s": step_s[0] if step_s else None,
+            "median_step_s": (
+                statistics.median(step_s[1:]) if len(step_s) > 1 else None
+            ),
+            "state_platforms": sorted({
+                d.platform
+                for x in jax.tree_util.tree_leaves(self.state)
+                for d in x.devices()
+            }),
             "t_load": s.t_load,
             "t_comp": s.t_comp,
             "t_overlap": s.t_overlap,

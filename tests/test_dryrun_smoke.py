@@ -10,7 +10,6 @@ pytestmark = pytest.mark.slow  # heavy; excluded from tier-1 (see pytest.ini)
 from jax.sharding import NamedSharding
 
 from repro.configs import get_config
-from repro.kernels.compat import cost_analysis_dict
 from repro.launch.input_specs import input_specs
 from repro.launch.mesh import dp_axes, make_host_mesh
 from repro.layers.common import ShardCtx
@@ -69,10 +68,7 @@ def test_lower_compile_smoke(arch, shape_kind):
             jf = jax.jit(make_decode_step(cfg, ctx), in_shardings=in_sh)
         with mesh:
             compiled = jf.lower(*specs).compile()
-        # cost_analysis() returns a list of per-program dicts on JAX
-        # 0.4.x and a flat dict on newer releases — the compat shim
-        # normalizes both (see repro.kernels.compat)
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         assert cost.get("flops", 0) > 0
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes > 0

@@ -152,16 +152,21 @@ def test_greedy_tokens_identical_to_solo_run(cfg, params, mode):
 
 
 def test_eos_retires_early_and_frees_slot(cfg, params):
-    req = _workload(cfg, 1, seed=5)[0]
+    req = _workload(cfg, 1, seed=8)[0]
     req.arrival = 0.0
     base = _engine(cfg, params)
     [full] = base.run([req])
-    assert len(full.tokens) >= 3
-    eos = full.tokens[2]
-    eng = _engine(cfg, params, eos_id=eos)
+    # eos = the first decoded token that has not occurred earlier in the
+    # output, so a greedy repeat of an earlier token cannot stop sooner
+    j = next(
+        i for i in range(1, len(full.tokens))
+        if full.tokens[i] not in full.tokens[:i]
+    )
+    assert j < len(full.tokens) - 1  # eos lands before the budget
+    eng = _engine(cfg, params, eos_id=full.tokens[j])
     [cut] = eng.run([Request(rid=0, prompt=req.prompt,
                              max_new_tokens=req.max_new_tokens)])
-    assert cut.tokens == full.tokens[:3]  # stops at first eos
+    assert cut.tokens == full.tokens[: j + 1]  # stops at first eos
     assert eng.free_slots == eng.max_batch
 
 

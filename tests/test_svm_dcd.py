@@ -1,5 +1,5 @@
 """DCD solver (LIBLINEAR-style) unit tests + the sparse CSR path:
-vectorized arena packing, the Pallas csr_dot kernel, and end-to-end
+vectorized arena packing, the on-device csr_dot, and end-to-end
 training through the ragged multi-producer pipeline."""
 import numpy as np
 import pytest
@@ -198,7 +198,7 @@ def test_dcd_csr_matches_dense_solver(tmp_path):
 def test_svm_end_to_end_through_ragged_pipeline(tmp_path):
     """The acceptance path: sparse store → LIRS shuffler → multi-producer
     ragged pipeline (ring-recycled arenas) → vectorized CSR packing → DCD,
-    with the Pallas csr_dot kernel bit-exact against the jnp reference on
+    with the on-device csr_dot bit-exact against the jnp reference on
     the trained weights."""
     import jax.numpy as jnp
 
@@ -239,7 +239,7 @@ def test_svm_end_to_end_through_ragged_pipeline(tmp_path):
     full = pack_csr_batch(store.read_batch_ragged(np.arange(n)), dim)
     xs, ys = csr_to_dense(full, dim)
     assert solver.accuracy(xs, ys) > 0.9
-    # Pallas kernel bit-exact vs the jnp reference on the trained weights
+    # csr_dot bit-exact vs the jnp reference on the trained weights
     idx2d, val2d = pad_csr(full)
     w32 = jnp.asarray(solver.w, jnp.float32)
     kernel = ops.csr_dot(jnp.asarray(idx2d), jnp.asarray(val2d), w32)

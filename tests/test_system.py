@@ -51,6 +51,11 @@ def test_training_reduces_loss(token_store):
     assert all(np.isfinite(l) for l in losses)
     # Eq.1 accounting is live
     assert summary["t_comp"] > 0 and summary["t_load"] > 0
+    # the summary carries what a chip run checks: every loss, step wall
+    # times, and where the train state lives
+    assert summary["losses"] == losses
+    assert summary["first_step_s"] > 0 and summary["median_step_s"] > 0
+    assert summary["state_platforms"] == ["cpu"]
 
 
 def test_preemption_resume_completes(token_store, tmp_path):
