@@ -1,0 +1,28 @@
+"""Published peaks of each chip the benchmark runs on, by ``device_kind``.
+
+TPU v5e: Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/
+v5e): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s.  JAX
+reports the chip's ``device_kind`` as "TPU v5 lite".  A device that is
+not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to benchmarks/chip/peaks.py with their source"
+        ) from None

@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU, at sizes a test run can hold."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[3]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
