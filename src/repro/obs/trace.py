@@ -19,6 +19,12 @@ Design constraints (ISSUE 8):
 * **Monotonic clocks.**  All timestamps come from
   ``time.perf_counter_ns`` — the same clock the pipeline's Eq. 1
   accounting uses, so traces and stats can never disagree.
+* **Mirrored onto the profiler's clock.**  While recording is on, every
+  recorded span also enters a ``jax.profiler.TraceAnnotation`` of the
+  same name and args, so a ``jax.profiler`` trace shows each span on
+  the host lane beside the device's work.  JAX is imported when
+  recording starts, never at module import; without JAX the ring
+  records alone.
 
 Export is the Chrome trace-event format (``{"traceEvents": [...]}``):
 open the file in https://ui.perfetto.dev or ``chrome://tracing``.
@@ -210,7 +216,8 @@ class Span:
     *both* modes — pipeline stats are fed from it — while the ring event
     is recorded only when tracing was enabled at acquisition."""
 
-    __slots__ = ("name", "cat", "args", "_record", "_t0", "duration_s")
+    __slots__ = ("name", "cat", "args", "_record", "_t0", "_mirror",
+                 "duration_s")
 
     def __init__(self):
         self.name = ""
@@ -218,9 +225,13 @@ class Span:
         self.args: Optional[dict] = None
         self._record = False
         self._t0 = 0
+        self._mirror = None
         self.duration_s = 0.0
 
     def __enter__(self) -> "Span":
+        if self._record and _annotation is not None:
+            self._mirror = _annotation(self.name, **(self.args or {}))
+            self._mirror.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -228,6 +239,9 @@ class Span:
         t0 = self._t0
         dur = time.perf_counter_ns() - t0
         self.duration_s = dur * 1e-9
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+            self._mirror = None
         if self._record and _enabled:
             _ring().push(
                 _recorder.name_id(self.name),
@@ -270,6 +284,19 @@ _enabled = False
 _recorder: Optional[TraceRecorder] = None
 _generation = 0
 _state_lock = threading.Lock()
+# jax.profiler.TraceAnnotation once recording has started (None before,
+# and where JAX is not installed)
+_annotation = None
+
+
+def _load_annotation() -> None:
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return
+        _annotation = TraceAnnotation
 
 
 def _ring() -> _ThreadRing:
@@ -324,6 +351,7 @@ def instant(name: str, cat: str = "", args: Optional[dict] = None) -> None:
 def enable(capacity_per_thread: int = DEFAULT_RING_CAPACITY) -> TraceRecorder:
     """Start recording into a fresh :class:`TraceRecorder`."""
     global _enabled, _recorder, _generation
+    _load_annotation()
     with _state_lock:
         _recorder = TraceRecorder(capacity_per_thread)
         _generation += 1
@@ -345,6 +373,7 @@ def resume() -> TraceRecorder:
     thread's already-faulted ring, so toggling around a measured region
     costs a flag flip, not a ring reallocation."""
     global _enabled, _recorder, _generation
+    _load_annotation()
     with _state_lock:
         if _recorder is None:
             _recorder = TraceRecorder(DEFAULT_RING_CAPACITY)

@@ -15,6 +15,13 @@ allocated exactly once (``init_decode_cache`` at construction — the
    the whole arena; every row appends at its own position.  Finished
    rows (budget reached / EOS) free their slots immediately.
 
+Traced, a step is one ``serve/step`` span holding a ``serve/admit`` per
+admission (``serve/features``, then ``serve/prefill`` through the
+first-token sync), ``serve/decode`` through the next tokens' copy to the
+host, and ``serve/emit``, the per-slot append and retire loop.  Two
+counters run always: ``Completion.admitted`` (the end of the queue
+wait) and ``slot_steps`` (occupied slots summed over decode steps).
+
 Both modes run the *same* per-step computation over the same arena
 shape; they differ only in when a free slot may be refilled — the
 benchmark's comparison is therefore pure scheduling.  Requests may
@@ -133,6 +140,9 @@ class ServeEngine:
         # counters
         self.steps = 0
         self.decode_steps = 0
+        # occupied slots summed over decode steps: / decode_steps is the
+        # mean batch a decode step carried
+        self.slot_steps = 0
         self.prefills = 0
         self.generated_tokens = 0
 
@@ -164,25 +174,32 @@ class ServeEngine:
 
     def _admit_one(self, req: Request, slot: int) -> None:
         now = self.clock.now()
-        if self.feature_cache is not None and req.feature_ids is not None:
-            self.feature_cache.fetch(req.feature_ids, now)
-        padded = np.zeros((1, self.prompt_capacity), np.int32)
-        padded[0, : len(req.prompt)] = req.prompt
-        with _trace.span("serve/prefill", "serve"):
-            pre, logits = self._prefill(
-                self.params, jnp.asarray(padded),
-                jnp.asarray([len(req.prompt)], jnp.int32),
+        with _trace.span(
+            "serve/admit", "serve",
+            args={"rid": req.rid} if _trace.enabled() else None,
+        ):
+            if self.feature_cache is not None and req.feature_ids is not None:
+                with _trace.span("serve/features", "serve"):
+                    self.feature_cache.fetch(req.feature_ids, now)
+            padded = np.zeros((1, self.prompt_capacity), np.int32)
+            padded[0, : len(req.prompt)] = req.prompt
+            with _trace.span("serve/prefill", "serve"):
+                pre, logits = self._prefill(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray([len(req.prompt)], jnp.int32),
+                )
+                self.arena = self._write_slot(self.arena, slot, pre)
+                first = int(jnp.argmax(logits[0], -1))
+            # the first token is on the host only after the argmax sync
+            first_at = self.clock.now()
+            self._cur[slot, 0] = first
+            self.slots[slot] = _Slot(
+                request=req, tokens=[first], admitted=now, first_token=first_at
             )
-            self.arena = self._write_slot(self.arena, slot, pre)
-        first = int(jnp.argmax(logits[0], -1))
-        self._cur[slot, 0] = first
-        self.slots[slot] = _Slot(
-            request=req, tokens=[first], admitted=now, first_token=now
-        )
-        self.prefills += 1
-        self.generated_tokens += 1
-        if self._finished(self.slots[slot]):
-            self._retire(slot, now)
+            self.prefills += 1
+            self.generated_tokens += 1
+            if self._finished(self.slots[slot]):
+                self._retire(slot, first_at)
 
     def _admit(self) -> int:
         admitted = 0
@@ -211,6 +228,7 @@ class ServeEngine:
                 rid=s.request.rid,
                 tokens=s.tokens,
                 arrival=s.request.arrival,
+                admitted=s.admitted,
                 first_token=s.first_token,
                 finished=finished,
             )
@@ -218,27 +236,33 @@ class ServeEngine:
 
     def step(self) -> None:
         """One engine step: admit, decode the whole arena once, retire."""
-        self._admit()
-        if self.slots:
-            with _trace.span("serve/decode", "serve"):
-                self.arena, logits = self._decode(
-                    self.params, self.arena, jnp.asarray(self._cur)
-                )
-            nxt = np.asarray(jnp.argmax(logits, -1), np.int32).reshape(-1)
-            self.decode_steps += 1
-            self.clock.advance(1.0)
-            done = self.clock.now()
-            for slot in list(self.slots):
-                tok = int(nxt[slot])
-                self._cur[slot, 0] = tok
-                s = self.slots[slot]
-                s.tokens.append(tok)
-                self.generated_tokens += 1
-                if self._finished(s):
-                    self._retire(slot, done)
-        else:
-            self.clock.advance(1.0)
-        self.steps += 1
+        with _trace.span(
+            "serve/step", "serve",
+            args={"step": self.steps} if _trace.enabled() else None,
+        ):
+            self._admit()
+            if self.slots:
+                with _trace.span("serve/decode", "serve"):
+                    self.arena, logits = self._decode(
+                        self.params, self.arena, jnp.asarray(self._cur)
+                    )
+                    nxt = np.asarray(jnp.argmax(logits, -1), np.int32).reshape(-1)
+                self.decode_steps += 1
+                self.slot_steps += len(self.slots)
+                self.clock.advance(1.0)
+                done = self.clock.now()
+                with _trace.span("serve/emit", "serve"):
+                    for slot in list(self.slots):
+                        tok = int(nxt[slot])
+                        self._cur[slot, 0] = tok
+                        s = self.slots[slot]
+                        s.tokens.append(tok)
+                        self.generated_tokens += 1
+                        if self._finished(s):
+                            self._retire(slot, done)
+            else:
+                self.clock.advance(1.0)
+            self.steps += 1
 
     def warmup(self) -> None:
         """Compile the prefill/slot-insert/decode programs (all fixed

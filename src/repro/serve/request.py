@@ -43,11 +43,14 @@ class Request:
 
 @dataclasses.dataclass
 class Completion:
-    """A finished request with its step-clock timeline."""
+    """A finished request with its timeline on the engine's clock:
+    arrival, admission to a slot (after any wait in the queue), first
+    token on the host, last token."""
 
     rid: int
     tokens: List[int]
     arrival: float
+    admitted: float
     first_token: float
     finished: float
 

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.obs import trace as _trace
+
 
 class DCDSolver:
     def __init__(self, dim: int, n: int, C: float = 1.0):
@@ -98,12 +100,18 @@ class DCDSolver:
         from repro.kernels import ops
         from repro.svm.sparse import pad_csr
 
-        idx2d, val2d = pad_csr(csr)
-        out = ops.csr_dot(
-            jnp.asarray(idx2d), jnp.asarray(val2d),
-            jnp.asarray(self.w, jnp.float32),
-        )
-        return np.asarray(out)
+        with _trace.span("svm/margins", "svm"):
+            with _trace.span("svm/pad", "svm"):
+                idx2d, val2d = pad_csr(csr)
+            # the host cast of the float64 weights and the three uploads
+            with _trace.span("svm/put", "svm"):
+                operands = (jnp.asarray(idx2d), jnp.asarray(val2d),
+                            jnp.asarray(self.w, jnp.float32))
+            with _trace.span("svm/csr_dot", "svm"):
+                out = ops.csr_dot(*operands)
+            # waits for the device, then copies the margins back
+            with _trace.span("svm/get", "svm"):
+                return np.asarray(out)
 
     def primal_objective_csr(self, csr) -> float:
         """Squared-hinge primal on one CSR batch, margins via the kernel."""

@@ -146,6 +146,8 @@ class Trainer:
                     if lc.fail_at_step >= 0 and self.global_step == lc.fail_at_step:
                         raise PreemptionError(f"simulated preemption @ {self.global_step}")
                     t0 = time.perf_counter()
+                    # dispatch through _log's float() sync: the step's
+                    # device work ends inside this span
                     with _trace.span(
                         "train/step",
                         "train",
@@ -153,10 +155,11 @@ class Trainer:
                         if _trace.enabled()
                         else None,
                     ):
-                        self.state, metrics = self.step_fn(self.state, batch)
-                    self.global_step += 1
-                    step_in_epoch += 1
-                    self._log(epoch, metrics, t0)
+                        with _trace.span("train/dispatch", "train"):
+                            self.state, metrics = self.step_fn(self.state, batch)
+                        self.global_step += 1
+                        step_in_epoch += 1
+                        self._log(epoch, metrics, t0)
                     if self.ckpt and self.global_step % lc.ckpt_every == 0:
                         self._save(epoch, step_in_epoch)
                     if lc.max_steps and self.global_step >= lc.max_steps:
@@ -184,11 +187,12 @@ class Trainer:
         )
 
     def _log(self, epoch: int, metrics: Dict, t0: float):
-        rec = {
-            "step": self.global_step,
-            "epoch": epoch,
-            **{k: float(v) for k, v in metrics.items()},
-        }
+        with _trace.span("train/sync", "train"):
+            rec = {
+                "step": self.global_step,
+                "epoch": epoch,
+                **{k: float(v) for k, v in metrics.items()},
+            }
         # float() above waited for the step: wall time of the dispatched
         # step, compile included on the first
         rec["step_s"] = time.perf_counter() - t0

@@ -156,6 +156,148 @@ def test_trace_thread_safety_and_chrome_schema(tmp_path):
     assert doc["traceEvents"][-1] == loaded["traceEvents"][-1]
 
 
+# ------------------------------------------ the mirror on the profiler
+def _innermost_parents(events):
+    """name → the name of the shortest other event containing it (same
+    lane), from ``(lane, name, start, end, args)`` tuples."""
+    out = {}
+    for lane, name, s, e, _ in events:
+        around = [(e2 - s2, n2) for l2, n2, s2, e2, _ in events
+                  if l2 == lane and n2 != name and s2 <= s and e <= e2]
+        out[name] = min(around)[1] if around else None
+    return out
+
+
+def _xplane_host_events(log_dir, names):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(
+        os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            for e in ln.events:
+                if e.name in names:
+                    out.append((ln.name, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_recorded_spans_mirror_onto_the_profiler_trace(tmp_path):
+    """Recording on, spans and timed spans come out in the xplane host
+    plane with their names, their args and the ring's nesting; recording
+    off, nothing is mirrored, though the profiler is taking a trace."""
+    jax = pytest.importorskip("jax")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.span("serve/emit", "serve"):
+            pass
+        with trace.timed("pipeline/wait", "pipeline"):
+            pass
+        rec = trace.enable(capacity_per_thread=64)
+        with trace.span("serve/admit", "serve", args={"rid": 7}):
+            with trace.span("serve/prefill", "serve"):
+                jax.numpy.ones(4).block_until_ready()
+            with trace.timed("pipeline/fetch", "pipeline"):
+                pass
+        trace.disable()
+    finally:
+        jax.profiler.stop_trace()
+    names = {"serve/admit", "serve/prefill", "pipeline/fetch",
+             "serve/emit", "pipeline/wait"}
+    mirrored = _xplane_host_events(str(tmp_path), names)
+    ring = [(e["tid"], e["name"], e["ts"], e["ts"] + e["dur"],
+             e.get("args", {})) for e in rec.drain()]
+    assert sorted(m[1] for m in mirrored) == sorted(r[1] for r in ring) == [
+        "pipeline/fetch", "serve/admit", "serve/prefill"]
+    assert {m[1]: m[4] for m in mirrored}["serve/admit"] == {"rid": 7}
+    assert {r[1]: r[4] for r in ring}["serve/admit"] == {"rid": 7}
+    nesting = {"serve/admit": None, "serve/prefill": "serve/admit",
+               "pipeline/fetch": "serve/admit"}
+    assert _innermost_parents(mirrored) == _innermost_parents(ring) == nesting
+
+
+def test_obs_imports_jax_only_when_recording_starts():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    code = ("import sys; from repro.obs import trace; "
+            "print('jax' in sys.modules); trace.enable(); "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True, timeout=120,
+                         env={**os.environ, "PYTHONPATH": src})
+    assert out.stdout.split() == ["False", "True"]
+
+
+def test_spans_added_to_hot_paths_cost_nothing_with_tracing_off(monkeypatch):
+    """With tracing off, one engine step, one ``Trainer`` step and one
+    ``margins_csr`` call push no ring event and acquire no span of their
+    own; the pipeline's timed spans measure as they always do."""
+    jax = pytest.importorskip("jax")
+    from repro.configs.granite_3_8b import smoke_config
+    from repro.models import model as model_lib
+    from repro.serve import Request, ServeEngine
+    from repro.svm.dcd import DCDSolver
+    from repro.svm.sparse import CSRBatch
+    from repro.train.loop import Trainer, TrainLoopConfig, make_shuffler
+
+    rec = trace.enable(capacity_per_thread=64)
+    trace.disable()
+    acquired = []
+    real_acquire = trace._acquire
+
+    def counting(name, *a):
+        acquired.append(name)
+        return real_acquire(name, *a)
+
+    monkeypatch.setattr(trace, "_acquire", counting)
+    cfg = smoke_config()
+
+    eng = ServeEngine(cfg, model_lib.init_params(cfg, jax.random.PRNGKey(0)),
+                      max_batch=2, prompt_capacity=8, max_new_tokens=4)
+    eng.submit(Request(rid=0, prompt=np.arange(1, 6, dtype=np.int32),
+                       max_new_tokens=3))
+    eng.step()
+    assert eng.prefills == 1 and eng.decode_steps == 1
+    assert acquired == []
+
+    rows = np.random.default_rng(0).integers(0, 64, (8, 17)).astype(np.int32)
+    trainer = Trainer(
+        cfg.replace(vocab_size=64),
+        lambda idx: {"tokens": rows[idx, :-1], "labels": rows[idx, 1:]},
+        make_shuffler("lirs", 8, 2, seed=0),
+        TrainLoopConfig(epochs=1, max_steps=1),
+    )
+    assert trainer.train()["steps"] == 1
+    assert acquired and {n.split("/")[0] for n in acquired} == {"pipeline"}
+    assert trainer.pipeline.stats.t_load > 0
+    assert trainer.pipeline.stats.t_wait > 0
+    del acquired[:]
+
+    solver = DCDSolver(32, 2)
+    solver.w = np.arange(32, dtype=np.float64)
+    m = solver.margins_csr(CSRBatch(
+        indices=np.array([1, 3, 2], np.int32),
+        values=np.array([1.0, 2.0, 0.5], np.float32),
+        row_ptr=np.array([0, 2, 3], np.int32),
+        labels=np.ones(2, np.float32)))
+    np.testing.assert_allclose(m, [7.0, 1.0])
+    assert acquired == []
+    assert rec.drain() == []
+
+
 # ------------------------------------------------------------- metrics
 def test_histogram_bucket_units():
     """Bucket k's upper bound is 1 µs · 2^k — the drift between an

@@ -126,6 +126,57 @@ def test_no_slot_leak_after_mixed_workload(cfg, params, mode):
         assert c.arrival <= c.first_token <= c.finished
 
 
+def _all_due_at_once(cfg, n):
+    reqs = _workload(cfg, n, seed=5)
+    for r in reqs:
+        r.arrival = 0.0
+    return reqs
+
+
+def test_timeline_orders_arrival_admission_first_token_finish(cfg, params):
+    """More requests than slots, all due at once: the first two take the
+    slots at once, every later one waits in the queue, and each
+    timeline runs arrival ≤ admitted ≤ first token ≤ finished."""
+    eng = _engine(cfg, params, max_batch=2)
+    comps = sorted(eng.run(_all_due_at_once(cfg, 6)), key=lambda c: c.rid)
+    for c in comps:
+        assert c.arrival <= c.admitted <= c.first_token <= c.finished
+    assert [c.admitted - c.arrival > 0 for c in comps] == [False] * 2 + [True] * 4
+
+
+class _TickClock(StepClock):
+    """A clock that moves on at every reading, as a wall clock does."""
+
+    def now(self) -> float:
+        self.advance(1e-3)
+        return super().now()
+
+
+def test_first_token_is_stamped_after_its_prefill(cfg, params):
+    eng = _engine(cfg, params, max_batch=2, clock=_TickClock())
+    for c in eng.run(_workload(cfg, 5, seed=6)):
+        assert c.arrival <= c.admitted < c.first_token <= c.finished
+
+
+def test_slot_steps_count_the_occupied_slots_of_each_decode(cfg, params):
+    """``slot_steps / decode_steps`` is the mean number of occupied slots
+    a decode step carried, counted here by hand at every decode."""
+    eng = _engine(cfg, params, max_batch=3)
+    occupied = []
+    real_decode = eng._decode
+
+    def counting(*a):
+        occupied.append(len(eng.slots))
+        return real_decode(*a)
+
+    eng._decode = counting
+    eng.run(_workload(cfg, 10, load=1.5, seed=7))
+    assert len(occupied) == eng.decode_steps > 0
+    assert eng.slot_steps == sum(occupied)
+    assert eng.slot_steps / eng.decode_steps == pytest.approx(np.mean(occupied))
+    assert 1 < np.mean(occupied) <= eng.max_batch
+
+
 def test_slots_reused_not_grown(cfg, params):
     """More requests than slots forces every slot through multiple
     admit/retire cycles; prefills count proves reuse, not growth."""
