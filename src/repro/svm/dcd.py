@@ -12,6 +12,17 @@ path (repro.svm.sparse) without densifying: the sequential dual updates
 touch only each instance's nonzeros, and the O(B·nnz) batch inner
 products (``margins_csr``) run on-device through ``ops.csr_dot``, a
 gather-and-reduce over the dense weight vector.
+
+The weights: ``w`` is a float64 vector on the host.  Assigning
+``solver.w = v`` copies ``v``; reading ``solver.w`` gives a read-only
+view, so an in-place write from outside raises ``ValueError`` (assign a
+new vector instead).  ``margins_csr`` keeps a float32 copy of ``w`` on
+the device and uploads it again only after ``w`` has changed: by
+assignment, ``solve_block`` or ``solve_block_csr``.  Scoring many
+batches against one ``w`` uploads it once; training, which changes
+``w`` between calls, uploads it on every call.  Two counters, always
+on: ``margins_calls`` (calls to ``margins_csr``) and ``w_uploads``
+(uploads of ``w`` among them).
 """
 from __future__ import annotations
 
@@ -23,12 +34,28 @@ from repro.obs import trace as _trace
 class DCDSolver:
     def __init__(self, dim: int, n: int, C: float = 1.0):
         self.C = C
-        self.w = np.zeros(dim)
+        self._w = np.zeros(dim)
+        self._w_dev = None  # float32 copy of _w on the device; None = stale
         self.alpha = np.zeros(n)
+        self.margins_calls = 0
+        self.w_uploads = 0
+
+    @property
+    def w(self) -> np.ndarray:
+        """The float64 weights, as a read-only view."""
+        view = self._w.view()
+        view.flags.writeable = False
+        return view
+
+    @w.setter
+    def w(self, value) -> None:
+        self._w = np.array(value, dtype=np.float64)
+        self._w_dev = None
 
     def solve_block(self, xs: np.ndarray, ys: np.ndarray, idx: np.ndarray, sweeps: int = 5):
         """Run DCD sweeps over the dual coordinates of one block."""
-        w, alpha, C = self.w, self.alpha, self.C
+        self._w_dev = None
+        w, alpha, C = self._w, self.alpha, self.C
         xb = xs[idx]
         yb = ys[idx]
         xsq = (xb * xb).sum(1) + 1.0 / (2 * C)
@@ -51,7 +78,8 @@ class DCDSolver:
         :meth:`solve_block`; each coordinate step touches only the
         instance's nonzeros, so a sweep is O(block nnz), not O(B·dim).
         """
-        w, alpha, C = self.w, self.alpha, self.C
+        self._w_dev = None
+        w, alpha, C = self._w, self.alpha, self.C
         rp = csr.row_ptr
         cols = csr.indices.astype(np.int64)
         vals = csr.values.astype(np.float64)
@@ -94,19 +122,33 @@ class DCDSolver:
         return np.bincount(rc[starts], combined * combined, minlength=b)
 
     def margins_csr(self, csr) -> np.ndarray:
-        """Batch inner products ``X w`` on-device (``ops.csr_dot``)."""
+        """Batch inner products ``X w`` on-device (``ops.csr_dot``).
+
+        The device sees ``w`` in float32, cast on the host.  That copy is
+        uploaded on the first call and again only after ``w`` has changed
+        (assignment or a solve); otherwise a call uploads just the padded
+        batch.  Each call adds one to ``margins_calls``, each upload of
+        ``w`` one to ``w_uploads``.
+        """
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops
         from repro.svm.sparse import pad_csr
 
+        self.margins_calls += 1
         with _trace.span("svm/margins", "svm"):
             with _trace.span("svm/pad", "svm"):
                 idx2d, val2d = pad_csr(csr)
-            # the host cast of the float64 weights and the three uploads
+            # the batch's uploads, and w's (with its host cast) if stale
             with _trace.span("svm/put", "svm"):
+                if self._w_dev is None:
+                    with _trace.span("svm/put_w", "svm"):
+                        self._w_dev = jax.device_put(
+                            self._w.astype(np.float32))
+                    self.w_uploads += 1
                 operands = (jnp.asarray(idx2d), jnp.asarray(val2d),
-                            jnp.asarray(self.w, jnp.float32))
+                            self._w_dev)
             with _trace.span("svm/csr_dot", "svm"):
                 out = ops.csr_dot(*operands)
             # waits for the device, then copies the margins back

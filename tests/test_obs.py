@@ -298,6 +298,33 @@ def test_spans_added_to_hot_paths_cost_nothing_with_tracing_off(monkeypatch):
     assert rec.drain() == []
 
 
+def test_margins_csr_records_the_upload_of_an_unchanged_w_once():
+    """Ring on, two ``margins_csr`` calls with one ``w``: two
+    ``svm/put`` spans, and ``svm/put_w`` inside the first alone."""
+    pytest.importorskip("jax")
+    from repro.svm.dcd import DCDSolver
+    from repro.svm.sparse import CSRBatch
+
+    solver = DCDSolver(32, 2)
+    solver.w = np.arange(32, dtype=np.float64)
+    csr = CSRBatch(indices=np.array([1, 3, 2], np.int32),
+                   values=np.array([1.0, 2.0, 0.5], np.float32),
+                   row_ptr=np.array([0, 2, 3], np.int32),
+                   labels=np.ones(2, np.float32))
+    rec = trace.enable(capacity_per_thread=64)
+    for _ in range(2):
+        np.testing.assert_allclose(solver.margins_csr(csr), [7.0, 1.0])
+    trace.disable()
+    events = sorted(rec.drain(), key=lambda e: e["ts"])
+    puts = [e for e in events if e["name"] == "svm/put"]
+    put_w = [e for e in events if e["name"] == "svm/put_w"]
+    assert len(puts) == 2 and len(put_w) == 1
+    inner, first = put_w[0], puts[0]
+    assert first["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= first["ts"] + first["dur"]
+    assert solver.w_uploads == 1 and solver.margins_calls == 2
+
+
 # ------------------------------------------------------------- metrics
 def test_histogram_bucket_units():
     """Bucket k's upper bound is 1 µs · 2^k — the drift between an

@@ -281,3 +281,108 @@ def test_svm_ragged_pipeline_convergence_tier(tmp_path):
     assert abs(obj_ragged - obj_dense) / obj_dense < 1e-3
     assert ragged.accuracy(xs, ys) > 0.95
     store.close()
+
+
+# ------------------------------------------- the device copy of w
+def _csr_batch(seed=0, rows=6, dim=48, nnz=(1, 9)):
+    from repro.svm.sparse import CSRBatch
+
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(nnz[0], nnz[1], size=rows)
+    row_ptr = np.concatenate(([0], np.cumsum(lens))).astype(np.int32)
+    return CSRBatch(
+        indices=rng.integers(0, dim, size=row_ptr[-1]).astype(np.int32),
+        values=rng.normal(size=row_ptr[-1]).astype(np.float32),
+        row_ptr=row_ptr,
+        labels=np.where(rng.random(rows) < 0.5, -1.0, 1.0).astype(np.float32),
+    )
+
+
+def _fresh_margins(w, csr):
+    """``ops.csr_dot`` over a fresh float32 upload of ``w``."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    from repro.svm.sparse import pad_csr
+
+    idx2d, val2d = pad_csr(csr)
+    return np.asarray(ops.csr_dot(jnp.asarray(idx2d), jnp.asarray(val2d),
+                                  jnp.asarray(w, jnp.float32)))
+
+
+@pytest.mark.parametrize("calls", [1, 2, 5])
+def test_margins_csr_uploads_an_unchanged_w_once(calls):
+    dim = 48
+    solver = DCDSolver(dim, 6)
+    solver.w = np.random.default_rng(1).normal(size=dim)
+    for k in range(calls):
+        csr = _csr_batch(seed=k, dim=dim)
+        np.testing.assert_array_equal(solver.margins_csr(csr),
+                                      _fresh_margins(solver.w, csr))
+    assert solver.w_uploads == 1
+    assert solver.margins_calls == calls
+
+
+def _change_by_assignment(solver, csr, xs, ys):
+    solver.w = solver.w + np.linspace(-1.0, 1.0, len(solver.w))
+
+
+def _change_by_solve_block(solver, csr, xs, ys):
+    solver.solve_block(xs, ys, np.arange(len(ys)), sweeps=2)
+
+
+def _change_by_solve_block_csr(solver, csr, xs, ys):
+    solver.solve_block_csr(csr, np.arange(len(ys)), sweeps=2)
+
+
+@pytest.mark.parametrize("change", [_change_by_assignment,
+                                    _change_by_solve_block,
+                                    _change_by_solve_block_csr])
+def test_margins_csr_uploads_again_after_w_changes(change):
+    from repro.svm.sparse import csr_to_dense
+
+    dim = 48
+    csr = _csr_batch(seed=3, dim=dim)
+    xs, ys = csr_to_dense(csr, dim)
+    solver = DCDSolver(dim, len(ys))
+    solver.w = np.random.default_rng(2).normal(size=dim) * 0.1
+    before = solver.margins_csr(csr)
+    w_before = solver.w.copy()
+    change(solver, csr, xs, ys)
+    assert not np.array_equal(solver.w, w_before)
+    after = solver.margins_csr(csr)
+    assert solver.w_uploads == 2 and solver.margins_calls == 2
+    assert not np.array_equal(after, before)
+    fresh = DCDSolver(dim, len(ys))
+    fresh.w = solver.w
+    np.testing.assert_array_equal(after, fresh.margins_csr(csr))
+    np.testing.assert_array_equal(after, _fresh_margins(solver.w, csr))
+
+
+def test_primal_objective_csr_reflects_a_solve():
+    from repro.svm.sparse import csr_to_dense
+
+    dim = 48
+    csr = _csr_batch(seed=4, rows=12, dim=dim)
+    xs, ys = csr_to_dense(csr, dim)
+    solver = DCDSolver(dim, len(ys))
+    first = solver.primal_objective_csr(csr)  # w = 0: uploads the zeros
+    assert first == pytest.approx(solver.primal_objective(xs, ys))
+    solver.solve_block_csr(csr, np.arange(len(ys)), sweeps=3)
+    second = solver.primal_objective_csr(csr)
+    assert second < first
+    assert second == pytest.approx(solver.primal_objective(xs, ys), rel=1e-5)
+    assert solver.w_uploads == 2
+
+
+def test_w_refuses_in_place_writes():
+    solver = DCDSolver(8, 2)
+    with pytest.raises(ValueError):
+        solver.w[3] = 1.0
+    with pytest.raises(ValueError):
+        solver.w += 1.0
+    v = np.ones(8)
+    solver.w = v
+    v[0] = 5.0  # the setter copied: the solver does not see this write
+    assert solver.w[0] == 1.0
+    np.testing.assert_array_equal(solver.w, np.ones(8))
