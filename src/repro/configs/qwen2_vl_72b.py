@@ -1,6 +1,7 @@
 """qwen2-vl-72b [vlm]: M-RoPE, dynamic resolution (frontend STUB).
 
-80L d_model=8192 64H (GQA kv=8) d_ff=29568 vocab=152064 [arXiv:2409.12191].
+80L d_model=8192 64H (GQA kv=8) d_ff=29568 vocab=152064 [arXiv:2409.12191];
+q/k/v projections carry a bias, as in every Qwen2 decoder.
 The vision frontend is a stub: ``input_specs()`` provides 3-axis position
 ids (temporal, height, width) consumed by M-RoPE; patch embeddings would
 occupy token positions.  M-RoPE sections (16, 24, 24) over head_dim/2.
@@ -22,6 +23,7 @@ def full_config() -> ModelConfig:
         activation="swiglu",
         stages=((("attn",), 80),),
         mrope_sections=(16, 24, 24),
+        qkv_bias=True,
     )
 
 
@@ -38,4 +40,5 @@ def smoke_config() -> ModelConfig:
         activation="swiglu",
         stages=((("attn",), 2),),
         mrope_sections=(2, 3, 3),
+        qkv_bias=True,
     )

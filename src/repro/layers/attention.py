@@ -25,20 +25,30 @@ from repro.layers.common import dense_init
 NEG_INF = -1e30
 
 
-def init_attn(rng, d_model: int, num_heads: int, num_kv_heads: int, head_dim: int, dtype):
+def init_attn(rng, d_model: int, num_heads: int, num_kv_heads: int, head_dim: int, dtype,
+              qkv_bias: bool = False):
     ks = jax.random.split(rng, 4)
-    return {
+    p = {
         "wq": dense_init(ks[0], (d_model, num_heads, head_dim), dtype),
         "wk": dense_init(ks[1], (d_model, num_kv_heads, head_dim), dtype),
         "wv": dense_init(ks[2], (d_model, num_kv_heads, head_dim), dtype),
         "wo": dense_init(ks[3], (num_heads, head_dim, d_model), dtype),
     }
+    if qkv_bias:
+        p["bq"] = jnp.zeros((num_heads, head_dim), dtype)
+        p["bk"] = jnp.zeros((num_kv_heads, head_dim), dtype)
+        p["bv"] = jnp.zeros((num_kv_heads, head_dim), dtype)
+    return p
 
 
 def qkv(params, x, dtype):
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dtype))
+    if "bq" in params:  # present only where the config asks for a bias
+        q = q + params["bq"].astype(dtype)
+        k = k + params["bk"].astype(dtype)
+        v = v + params["bv"].astype(dtype)
     return q, k, v
 
 
@@ -82,8 +92,9 @@ def full_attention(q, k, v, causal: bool = True):
 
 def blocked_attention(q, k, v, block: int = 1024):
     """Flash-style causal attention: scan over query blocks, online softmax
-    over key blocks.  Never materializes the full (S,T) score matrix —
-    the memory-roofline optimization path (§Perf)."""
+    over key blocks.  Never materializes the full (S,T) score matrix, in
+    the forward pass or for the backward one — the memory-roofline
+    optimization path (§Perf)."""
     b, s, h, d = q.shape
     k, v = _expand_kv(q, k, v)
     if s % block != 0 or s <= block:
@@ -95,6 +106,9 @@ def blocked_attention(q, k, v, block: int = 1024):
     def per_qblock(carry, xs):
         qi, idx = xs
 
+        # recomputed in the backward pass: the scan then keeps only its
+        # carries, not every block's scores (O(S·d) residuals, not O(S²))
+        @jax.checkpoint
         def inner(icarry, jxs):
             m, l, acc = icarry
             kj, vj, jdx = jxs

@@ -6,8 +6,10 @@ supports three modes:
     prefill  — full sequence, emits a decode cache
     decode   — one token, consumes + re-emits its cache
 
-Blocks return ``(x, cache, aux)`` where aux is a scalar f32 auxiliary loss
-(MoE load-balancing; 0 elsewhere).
+Blocks return ``(x, cache, aux)`` where aux is a dict of f32 scalars that
+the model sums over layers: ``"aux"``, the auxiliary loss (MoE load
+balancing; 0 elsewhere), and for MoE blocks the slot counters
+``MOE_COUNTERS``.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ from repro.layers.positional import apply_rope
 from repro.models.config import ModelConfig
 
 ATTN_KINDS = ("attn", "local_attn", "enc_attn", "dec_attn", "moe")
+MOE_COUNTERS = moe_lib.COUNTERS
 
 
 def _slstm_ff(cfg: ModelConfig) -> int:
@@ -44,18 +47,18 @@ def init_block(rng, kind: str, cfg: ModelConfig):
     ks = jax.random.split(rng, 6)
     p: Dict[str, Any] = {"norm1": jnp.zeros((d,), dt)}
     if kind in ("attn", "local_attn", "enc_attn"):
-        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt)
+        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt, cfg.qkv_bias)
         p["norm2"] = jnp.zeros((d,), dt)
         p["ffn"] = init_ffn(ks[1], d, cfg.d_ff, cfg.activation, dt)
     elif kind == "dec_attn":
-        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt)
+        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt, cfg.qkv_bias)
         p["norm2"] = jnp.zeros((d,), dt)
         p["cross"] = attn.init_attn(ks[1], d, h, kv, hd, dt)
         p["norm3"] = jnp.zeros((d,), dt)
         p["ffn"] = init_ffn(ks[2], d, cfg.d_ff, cfg.activation, dt)
     elif kind == "moe":
         assert cfg.moe is not None
-        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt)
+        p["attn"] = attn.init_attn(ks[0], d, h, kv, hd, dt, cfg.qkv_bias)
         p["norm2"] = jnp.zeros((d,), dt)
         p["moe"] = moe_lib.init_moe(ks[1], cfg, cfg.moe, dt)
     elif kind == "rglru":
@@ -204,7 +207,7 @@ def apply_block(
 ):
     aux = aux or {}
     dt = cfg.compute_dtype
-    zero = jnp.zeros((), jnp.float32)
+    zero = {"aux": jnp.zeros((), jnp.float32)}
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
 
     if kind in ("attn", "local_attn", "enc_attn", "moe"):
@@ -218,7 +221,8 @@ def apply_block(
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
         if kind == "moe":
             y, m = moe_lib.apply_moe(p["moe"], h2, cfg, cfg.moe, dt)
-            return x + y, new_cache, m["moe_aux"]
+            return x + y, new_cache, {"aux": m["moe_aux"], **{
+                k: m[k] for k in MOE_COUNTERS}}
         y = apply_ffn(p["ffn"], h2, cfg.activation, dt, cfg.reduce_pet)
         return x + y, new_cache, zero
 

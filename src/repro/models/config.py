@@ -29,7 +29,7 @@ Stage = Tuple[Tuple[str, ...], int]  # (pattern, repeats)
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int  # the router's width: every expert of the layer
     experts_per_token: int
     d_ff_expert: int
     num_shared_experts: int = 0
@@ -40,6 +40,29 @@ class MoEConfig:
     # dispatch-einsum cost is O(tokens · group · k · cf · d): grouping the
     # sequence bounds it (0 = one group per sequence — quadratic in S!)
     group_size: int = 0
+    # this chip's expert share under expert parallelism: experts
+    # [first_expert, first_expert + held_experts) of the num_experts the
+    # router scores (0 = all of them).  Slots routed to other experts
+    # belong to other chips' shares and add nothing here.
+    held_experts: int = 0
+    first_expert: int = 0
+    # re-normalise the top-k gates to sum to one (DBRX does, Qwen-MoE not)
+    norm_topk_prob: bool = True
+    # weight of the load-balancing loss in the training loss
+    aux_loss_coef: float = 0.01
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.held_experts or self.num_experts
+
+    def __post_init__(self):
+        if not 0 <= self.first_expert < self.first_expert + self.held <= self.num_experts:
+            raise ValueError(
+                f"held experts [{self.first_expert}, "
+                f"{self.first_expert + self.held}) outside the "
+                f"{self.num_experts} the router scores"
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +89,7 @@ class ModelConfig:
     rope: bool = True
     rope_theta: float = 10000.0
     mrope_sections: Tuple[int, ...] = ()  # non-empty -> M-RoPE (qwen2-vl)
+    qkv_bias: bool = False  # q, k and v projections add a bias (Qwen2)
     # attention implementation: "full" materializes scores; "blocked" is the
     # flash-style online-softmax path (memory-roofline lever, §Perf)
     attn_impl: str = "full"

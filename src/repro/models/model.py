@@ -19,11 +19,9 @@ from repro.layers.positional import (
     rope_angles,
     sinusoidal,
 )
-from repro.models.blocks import apply_block, init_block
+from repro.models.blocks import MOE_COUNTERS, apply_block, init_block
 from repro.models.config import ModelConfig
 from repro.utils.tree import map_with_path
-
-AUX_LOSS_WEIGHT = 0.01
 
 
 # ------------------------------------------------------------------ init
@@ -108,19 +106,28 @@ def _stack_trees(trees):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
+def _add_stats(total, more):
+    """Sum two dicts of scalars key by key (a key missing counts 0)."""
+    return {k: total.get(k, 0.0) + more.get(k, 0.0) for k in {**total, **more}}
+
+
 def _run_stage_train(stage_params, pattern, x, cfg, aux, ctx):
+    """Returns the stage's output and its blocks' ``aux`` dicts summed
+    over layers: the auxiliary loss, and the MoE counters where the
+    pattern has MoE blocks."""
     def body(carry, lp):
-        x, aloss = carry
+        x, stats = carry
         for pi, kind in enumerate(pattern):
             x, _, a = apply_block(kind, lp[pi], x, cfg, "train", aux=aux, ctx=ctx)
-            aloss = aloss + a
-        return (x, aloss), None
+            stats = _add_stats(stats, a)
+        return (x, stats), None
 
     body = _remat_wrap(body, cfg)
-    carry = (x, jnp.zeros((), jnp.float32))
+    keys = ("aux",) + (MOE_COUNTERS if "moe" in pattern else ())
+    carry = (x, {k: jnp.zeros((), jnp.float32) for k in keys})
     if cfg.scan_layers:
-        (x, aloss), _ = jax.lax.scan(body, carry, stage_params)
-        return x, aloss
+        (x, stats), _ = jax.lax.scan(body, carry, stage_params)
+        return x, stats
     repeats = jax.tree_util.tree_leaves(stage_params)[0].shape[0]
     for i in range(repeats):  # unrolled: accurate cost_analysis (dry-run)
         carry, _ = body(carry, _layer_slice(stage_params, i))
@@ -203,7 +210,7 @@ def encode(cfg: ModelConfig, params, frames, ctx=None):
     aloss = jnp.zeros((), jnp.float32)
     for si, (pattern, repeats) in enumerate(enc_cfg.stages):
         x, a = _run_stage_train(params["encoder"]["stages"][si], pattern, x, cfg, {}, ctx)
-        aloss += a
+        aloss += a["aux"]
     return rms_norm(x, params["encoder"]["norm"], cfg.norm_eps), aloss
 
 
@@ -231,13 +238,13 @@ def forward_hidden(
             enc_out, enc_aux = encode(cfg, params, extras["encoder_frames"], ctx)
             aux["enc"] = enc_out
 
-    aloss = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
     new_caches = []
     for si, (pattern, repeats) in enumerate(cfg.stages):
         sp = params["stages"][si]
         if mode == "train":
             x, a = _run_stage_train(sp, pattern, x, cfg, aux, ctx)
-            aloss += a
+            stats = _add_stats(stats, a)
         elif mode == "prefill":
             x, c = _run_stage_prefill(sp, pattern, x, cfg, aux, ctx)
             new_caches.append(c)
@@ -245,7 +252,7 @@ def forward_hidden(
             x, c = _run_stage_decode(sp, pattern, x, cfg, aux, ctx, caches["stages"][si], pos)
             new_caches.append(c)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, new_caches, aloss
+    return x, new_caches, stats
 
 
 def _logits(cfg, params, hidden):
@@ -262,7 +269,7 @@ def _logits(cfg, params, hidden):
 def loss_fn(cfg: ModelConfig, params, batch, ctx=None, rng=None):
     tokens, labels = batch["tokens"], batch["labels"]
     extras = {k: v for k, v in batch.items() if k not in ("tokens", "labels")}
-    hidden, _, aloss = forward_hidden(cfg, params, tokens, "train", extras, ctx)
+    hidden, _, stats = forward_hidden(cfg, params, tokens, "train", extras, ctx)
 
     valid = (labels >= 0).astype(jnp.float32)
     safe_labels = jnp.maximum(labels, 0)
@@ -297,8 +304,11 @@ def loss_fn(cfg: ModelConfig, params, batch, ctx=None, rng=None):
     else:
         tot, cnt = ce(hidden, safe_labels, valid)
     loss = tot / jnp.maximum(cnt, 1.0)
-    metrics = {"ce": loss, "aux": aloss}
-    return loss + AUX_LOSS_WEIGHT * aloss, metrics
+    # the MoE counters, summed over layers, ride out with the metrics
+    metrics = {"ce": loss, **stats}
+    if cfg.moe is not None:
+        loss = loss + cfg.moe.aux_loss_coef * stats["aux"]
+    return loss, metrics
 
 
 # --------------------------------------------------------------- serving
